@@ -30,6 +30,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import paper_benchmarks as pb
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     fns = pb.ALL
     if args.only:

@@ -349,6 +349,15 @@ def brute_fused_sqdist(
     blocking philosophy) used for ground truth, reranking and the
     ``retrieval_cand`` recsys path on CPU.
     """
+    if qv.shape[0] == 1:
+        # XLA's CPU backend runs a one-row product as a matrix-vector kernel
+        # that rounds differently from the matrix-matrix kernel of a batch:
+        # a duplicate row keeps a query's scores bit-identical whether it is
+        # scored alone or inside a padded serving bucket
+        two = lambda a: None if a is None else jnp.concatenate([a, a])
+        return brute_fused_sqdist(
+            two(qv), two(qa), db_v, db_a, cfg, two(mask), chunk
+        )[:1]
     qv = qv.astype(jnp.float32)
     db_v = db_v.astype(jnp.float32)
     qsq = (qv * qv).sum(-1)[:, None]  # (B, 1)
@@ -360,7 +369,11 @@ def brute_fused_sqdist(
 
     def score_block(xv, xa):
         xsq = (xv * xv).sum(-1)[None, :]
-        sv2 = jnp.maximum(qsq + xsq - 2.0 * (qv @ xv.T), 0.0)
+        # HIGHEST: a default-precision f32 dot is one bf16 pass on the TPU,
+        # and the ‖q‖² + ‖x‖² − 2q·x cancellation then reorders near
+        # neighbours at sift magnitudes (this scan is the exact oracle)
+        qx = jnp.matmul(qv, xv.T, precision=jax.lax.Precision.HIGHEST)
+        sv2 = jnp.maximum(qsq + xsq - 2.0 * qx, 0.0)
         return fused_sqdist_from_sv2(sv2, qae, xa[None, :, :], cfg, me)
 
     if n_chunks == 1:

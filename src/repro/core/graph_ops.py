@@ -48,12 +48,10 @@ def reverse_neighbors(neighbors: Array, n_nodes: int, capacity: int) -> Array:
     order = jnp.argsort(key, stable=True)
     dst_s = key[order]
     src_s = src[order]
-    # Rank within each destination segment.
-    first_of_seg = jnp.concatenate(
-        [jnp.array([True]), dst_s[1:] != dst_s[:-1]]
-    )
-    seg_start = jnp.where(first_of_seg, jnp.arange(dst_s.shape[0]), 0)
-    seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
+    # Rank within each destination segment: offset from the segment's first
+    # edge. A binary search finds it (an associative scan over N·Γ edges
+    # takes the TPU compiler minutes at 100k nodes).
+    seg_start = jnp.searchsorted(dst_s, dst_s, side="left")
     rank = jnp.arange(dst_s.shape[0]) - seg_start
     keep = (rank < capacity) & (dst_s < n)
     safe_dst = jnp.where(keep, dst_s, n)  # out-of-range rows are dropped
@@ -108,9 +106,12 @@ def merge_pools(
 
     # Dedup by id: sort by (id asc, flag desc, dist asc) so the kept copy of a
     # duplicate id is the checked one (flags dominate: a checked node must not
-    # be re-expanded) and otherwise the closest one.
+    # be re-expanded) and otherwise the closest one. Flags are 0/1, so id and
+    # flag fold into one key 2·id + (flag == 0): a three-operand sort of a
+    # (128, 1600) pool takes the TPU compiler ~30 s, a two-operand one ~5 s.
     if flags is not None:
-        order = jnp.lexsort((dists, -flags.astype(jnp.int32), ids), axis=-1)
+        key = ids * 2 + (flags == 0).astype(ids.dtype)
+        order = jnp.lexsort((dists, key), axis=-1)
     else:
         order = jnp.lexsort((dists, ids), axis=-1)
     ids_s = jnp.take_along_axis(ids, order, axis=-1)

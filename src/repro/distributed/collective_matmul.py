@@ -12,8 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import named_axis_size
-
 Array = jax.Array
 
 
@@ -26,7 +24,7 @@ def ring_allreduce_matmul(
     w_local (K_s, N): the matching weight rows. Equivalent to
     ``psum(x_local @ w_local, axis)`` but decomposed for overlap.
     """
-    size = named_axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % size) for i in range(size)]
     partial = x_local @ w_local  # (B, N) local term
     acc = partial
@@ -43,7 +41,7 @@ def ring_reduce_scatter_matmul(
     The down-projection of sequence-parallel TP: each hop reduces one row
     chunk while the next chunk's add is still in flight.
     """
-    size = named_axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % size) for i in range(size)]
     y = x_local @ w_local  # (B, N) partial term (summand of the full result)

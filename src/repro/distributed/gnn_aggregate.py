@@ -17,8 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import named_axis_size
-
 Array = jax.Array
 
 
@@ -35,7 +33,7 @@ def ring_partitioned_aggregate(
     the running sum downstream and adds the local edges' contribution to the
     shard now in hand; after ``size-1`` hops device ``i`` holds shard ``i``.
     """
-    size = named_axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     assert n_nodes % size == 0, (n_nodes, size)
     rows = n_nodes // size

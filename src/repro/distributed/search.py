@@ -49,7 +49,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import lru_get
 from repro.core import routing as routing_mod
 from repro.core.auto import MetricConfig
-from repro.distributed import sharding as sharding_mod
 from repro.core.graph_ops import INF, INVALID
 from repro.core.help_graph import HelpConfig, build_help_graph
 from repro.core.routing import RoutingConfig
@@ -278,7 +277,7 @@ class ShardedStableIndex:
                 extra_specs += (P(None, None),)
         # interval targets carry a trailing replicated [lo, hi] axis
         qa_spec = P("data", None, None) if qa_ndim == 3 else P("data", None)
-        fn = sharding_mod.shard_map(
+        fn = jax.shard_map(
             local_search,
             mesh=mesh,
             in_specs=(

@@ -26,33 +26,6 @@ from repro.train.optim import AdafactorState, AdamWState, OptimConfig, SGDState
 PyTree = Any
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant shard_map: ``jax.shard_map`` when present (newer jax),
-    else ``jax.experimental.shard_map`` with its ``check_rep`` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def named_axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside shard_map.
-
-    ``jax.lax.axis_size`` on newer jax; on older versions ``psum(1, axis)``
-    constant-folds to the same Python int.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _div(n: int, k: int) -> bool:
     return n % k == 0
 

@@ -22,13 +22,20 @@ LUT tile 64 KiB + one-hot tile 2 MiB + codes/attr tiles ≲ 20 KiB ≪ VMEM,
 and the contraction dim S·K is a multiple of the 128-lane MXU tile.
 
 4-bit variant (``adc_scan4_scores``): codes arrive packed two-per-byte
-(K=16, one nibble each); the kernel body unpacks them **in-register**
-(`lo = c & 0xF`, `hi = c >> 4`, interleave) and contracts the same one-hot
-matmul against an S×16 LUT — the contraction dim shrinks 16× vs the 8-bit
-path (S·16 lanes), and HBM code traffic halves. Odd S pads one zero-LUT
-subspace so the pad nibble contributes nothing. The unpacked one-hot tile is
-identical to what the 8-bit kernel builds from pre-unpacked codes, so the
-two paths are bit-exact against each other (asserted in tests).
+(K=16, one nibble each); the kernel body unpacks them **in-register** and
+contracts the same one-hot matmul against an S×16 LUT — the contraction dim
+shrinks 16× vs the 8-bit path (S·16 lanes), and HBM code traffic halves.
+The unpack never interleaves nibbles along lanes (Mosaic refuses that
+reshape): each byte is broadcast over 32 one-hot columns, the first 16 test
+its low nibble and the next 16 its high nibble, which is already the
+subspace order 2i, 2i+1 of the LUT. Odd S pads one zero-LUT subspace so the
+pad nibble contributes nothing. The one-hot tile is identical to what the
+8-bit kernel builds from pre-unpacked codes, so the two paths are bit-exact
+against each other (asserted in tests).
+
+Both variants contract at ``Precision.HIGHEST``: the one-hot operand is
+exact in bf16 but the LUT is not, and a default-precision f32 dot on the
+TPU rounds it to bf16.
 """
 from __future__ import annotations
 
@@ -54,17 +61,23 @@ def _kernel(lut_ref, codes_ref, qlo_ref, qhi_ref, xa_ref, mask_ref, o_ref, *,
     codes = codes_ref[...]  # (bn, S) int32 — or (bn, S/2) packed nibbles
     bn = codes.shape[0]
     if packed:
-        # in-register nibble unpack: byte i holds subspaces (2i, 2i+1)
-        lo = codes & 0xF
-        hi = (codes >> 4) & 0xF
-        codes = jnp.stack([lo, hi], axis=-1).reshape(bn, n_subspaces)
-    col = jax.lax.broadcasted_iota(
-        jnp.int32, (bn, n_subspaces, n_centroids), 2
-    )
-    onehot = (col == codes[:, :, None]).astype(jnp.float32)
-    onehot = onehot.reshape(bn, n_subspaces * n_centroids)
+        # byte i holds subspaces (2i, 2i+1): column c of its 32 one-hot
+        # columns tests nibble c // 16 (shift 0 or 4) against centroid c % 16
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, (bn, n_subspaces // 2, 2 * n_centroids), 2
+        )
+        nibble = (codes[:, :, None] >> ((col // n_centroids) * 4)) & 0xF
+        onehot = nibble == col % n_centroids
+    else:
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, (bn, n_subspaces, n_centroids), 2
+        )
+        onehot = col == codes[:, :, None]
+    onehot = onehot.astype(jnp.float32).reshape(bn, n_subspaces * n_centroids)
     sv2 = jax.lax.dot_general(
-        lut, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        lut, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # MXU: (bb, bn) ADC partial-distance sums
     sv2 = jnp.maximum(sv2, 0.0)
     if mode == "l2":

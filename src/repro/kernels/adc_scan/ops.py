@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the fused ADC scan kernel.
 
-Selects Pallas compiled mode on TPU, interpret mode elsewhere (this container
-is CPU-only; interpret executes the kernel body in Python for correctness).
+Runs the Pallas kernel compiled on the accelerator and interpreted on the
+CPU backend (``kernels.common.interpret_mode``).
 ``packed=True`` routes to the 4-bit variant (codes two-per-byte, S×16 LUT).
 Also exposes a top-k convenience used by the quantized serving path.
 """
@@ -14,12 +14,9 @@ import jax.numpy as jnp
 
 from repro.kernels.adc_scan.adc_scan import adc_scan4_scores, adc_scan_scores
 from repro.kernels.adc_scan.ref import adc_scan4_ref, adc_scan_ref
+from repro.kernels.common import interpret_mode
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def adc_scan(
@@ -34,14 +31,14 @@ def adc_scan(
     block_n: int = 256,
     packed: bool = False,
 ) -> Array:
-    """(B, N) squared fused ADC distances (Pallas on TPU, interpret on CPU).
+    """(B, N) squared fused ADC distances (Pallas; interpreted on CPU).
     ``qa`` is (B, L) point targets or (B, L, 2) [lo, hi] interval targets.
     ``packed`` selects the 4-bit nibble-packed kernel variant."""
     fn = adc_scan4_scores if packed else adc_scan_scores
     return fn(
         lut, codes, qa, xa, alpha=alpha, mode=mode, mask=mask,
         block_b=block_b, block_n=block_n,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 

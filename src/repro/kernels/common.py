@@ -4,12 +4,23 @@ One canonical parse of the attribute-target operand: every scorer family
 accepts (B, L) point targets or (B, L, 2) [lo, hi] interval targets and
 lowers them to the two (B, L) bound tiles its kernel consumes — a single
 definition so the families can never disagree on the contract.
+
+``interpret_mode`` is the one place that decides how a Pallas kernel runs:
+interpreted on the CPU backend (tests), compiled everywhere else — a
+kernel the accelerator's compiler refuses fails loudly instead of falling
+back to the interpreter.
 """
 from __future__ import annotations
 
 import jax
 
 Array = jax.Array
+
+
+def interpret_mode() -> bool:
+    """True only on the CPU backend, where Pallas TPU kernels cannot
+    compile and run through the interpreter instead."""
+    return jax.default_backend() == "cpu"
 
 
 def split_targets(qa: Array) -> tuple[Array, Array]:

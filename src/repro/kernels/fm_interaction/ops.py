@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels.common import interpret_mode
 from repro.kernels.fm_interaction.fm_interaction import fm_interaction_pallas
 from repro.kernels.fm_interaction.ref import (
     fm_interaction_pairwise_ref,
@@ -13,10 +14,8 @@ Array = jax.Array
 
 
 def fm_interaction(emb: Array) -> Array:
-    """(B,) FM second-order term (Pallas on TPU, interpret elsewhere)."""
-    return fm_interaction_pallas(
-        emb, interpret=jax.default_backend() != "tpu"
-    )
+    """(B,) FM second-order term (compiled Pallas; interpreted on CPU)."""
+    return fm_interaction_pallas(emb, interpret=interpret_mode())
 
 
 __all__ = ["fm_interaction", "fm_interaction_ref", "fm_interaction_pairwise_ref"]

@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the fused AUTO scorer kernel.
 
-Selects Pallas compiled mode on TPU, interpret mode elsewhere (this container
-is CPU-only; interpret executes the kernel body in Python for correctness).
+Runs the Pallas kernel compiled on the accelerator and interpreted on the
+CPU backend (``kernels.common.interpret_mode``).
 Also exposes a top-k convenience used by the retrieval serving path.
 """
 from __future__ import annotations
@@ -11,14 +11,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import interpret_mode
 from repro.kernels.fused_auto.fused_auto import fused_auto_scores
 from repro.kernels.fused_auto.ref import fused_auto_ref
 
 Array = jax.Array
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def fused_auto(
@@ -33,12 +30,12 @@ def fused_auto(
     block_n: int = 256,
     block_m: int = 512,
 ) -> Array:
-    """(B, N) squared fused AUTO distances (Pallas on TPU, interpret on CPU).
+    """(B, N) squared fused AUTO distances (Pallas; interpreted on CPU).
     ``qa`` is (B, L) point targets or (B, L, 2) [lo, hi] interval targets."""
     return fused_auto_scores(
         qv, qa, xv, xa, alpha=alpha, mode=mode, mask=mask,
         block_b=block_b, block_n=block_n, block_m=block_m,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 

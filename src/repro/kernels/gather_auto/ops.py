@@ -5,6 +5,7 @@ from typing import Optional
 
 import jax
 
+from repro.kernels.common import interpret_mode
 from repro.kernels.gather_auto.gather_auto import gather_auto_scores
 from repro.kernels.gather_auto.ref import gather_auto_ref
 
@@ -24,7 +25,7 @@ def gather_auto(
     is (B, L) point targets or (B, L, 2) [lo, hi] interval targets."""
     return gather_auto_scores(
         qv, qa, cv, ca, alpha=alpha, mode=mode, mask=mask,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
 
 

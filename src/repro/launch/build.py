@@ -342,8 +342,10 @@ def main() -> None:
     from repro.api import Engine
     from repro.core.help_graph import HelpConfig
     from repro.data.synthetic import make_hybrid_dataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.quant import QUANT_MODES, QuantConfig
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="build + save a STABLE engine")
     ap.add_argument("--out", required=True, help="output index directory")
     ap.add_argument("--n", type=int, default=20_000)

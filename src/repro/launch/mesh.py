@@ -10,13 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # axis_types / AxisType only exist on newer jax; older versions default
-    # every axis to Auto anyway, which is what we want.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

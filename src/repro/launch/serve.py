@@ -45,10 +45,13 @@ def main() -> None:
     from repro.mutable import CompactionPolicy, MutableEngine
     from repro.obs import Tracer, dump_chrome_trace
     from repro.quant import QUANT_MODES, QuantConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import (
         Delete, Request, TenantPolicy, TenantRegistry, ThreadedServer,
         Upsert, serve_loop,
     )
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--index-dir", default=None,

@@ -145,6 +145,25 @@ class TestFusedMetric:
         a2 = A.brute_fused_sqdist(qv, qa, xv, xa, cfg, chunk=4096)
         np.testing.assert_allclose(np.asarray(a1), np.asarray(a2), rtol=1e-5)
 
+    @pytest.mark.parametrize("target", ["point", "interval", "masked"])
+    def test_brute_fused_solo_query_bit_identical_to_batch(self, target):
+        """A query scored alone gets the same bits as its row of a batch
+        (a serving bucket pads a solo query up to a batch)."""
+        qv, qa, xv, xa = rand_case(4, b=8, n=2000, m=128)
+        mask = None
+        if target == "interval":
+            qa = np.stack([qa, qa + 1], axis=-1)
+        elif target == "masked":
+            mask = (np.arange(8 * 5).reshape(8, 5) % 3 > 0).astype(np.int32)
+        cfg = MetricConfig(mode="auto", alpha=1.0)
+        batch = np.asarray(A.brute_fused_sqdist(qv, qa, xv, xa, cfg, mask))
+        for i in range(8):
+            solo = A.brute_fused_sqdist(
+                qv[i:i + 1], qa[i:i + 1], xv, xa, cfg,
+                None if mask is None else mask[i:i + 1],
+            )
+            np.testing.assert_array_equal(np.asarray(solo)[0], batch[i])
+
     def test_triangle_inequality_within_uniform_attrs(self):
         """§III-B3[c]: within an attribute-uniform subspace U is a scaled
         Euclidean metric, so the triangle inequality holds."""

@@ -130,7 +130,7 @@ def test_ring_collective_matmuls_match_psum():
         def f_psum(x, w):
             return jax.lax.psum(x @ w, "model")
 
-        from repro.distributed.sharding import shard_map
+        from jax import shard_map
         sm = lambda f: shard_map(
             f, mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
             out_specs=P(None, None), check_vma=False)
@@ -243,7 +243,7 @@ def test_ring_partitioned_gnn_aggregate_matches_segment_sum():
         def f(m, dd):
             return ring_partitioned_aggregate(m, dd, n_nodes, "model")
 
-        from repro.distributed.sharding import shard_map
+        from jax import shard_map
         got = shard_map(
             f, mesh=mesh, in_specs=(P("model", None), P("model")),
             out_specs=P("model", None), check_vma=False)(msgs, dst)
