@@ -1378,14 +1378,17 @@ def obs_sweep(fast: bool = True, n: int = 0) -> None:
     for tr in traces:
         root = tr.root
         total_ms = root.duration * 1e3
-        queue, batch = root.find("queue"), root.find("batch")
-        assert queue is not None and batch is not None, (
-            "every request trace carries queue + batch spans"
+        inbox, queue, batch = (root.find("serve.inbox"),
+                               root.find("serve.queue"),
+                               root.find("serve.flush"))
+        assert None not in (inbox, queue, batch), (
+            "every request trace carries inbox + queue + flush spans"
         )
-        # exact by construction: root is pinned to queue + batch
-        exact_err = abs(total_ms - (queue.duration + batch.duration) * 1e3)
+        # exact by construction: root is pinned to inbox + queue + flush
+        exact_err = abs(total_ms - (inbox.duration + queue.duration
+                                    + batch.duration) * 1e3)
         assert exact_err <= 1e-3, (
-            f"root span ({total_ms:.3f}ms) != queue + batch "
+            f"root span ({total_ms:.3f}ms) != inbox + queue + flush "
             f"(err {exact_err:.4f}ms)"
         )
         max_exact_err_ms = max(max_exact_err_ms, exact_err)
@@ -1420,18 +1423,19 @@ def obs_sweep(fast: bool = True, n: int = 0) -> None:
     p_traces = p_tracer.traces()
     assert p_traces, "partitioned serve run must record traces"
     root = p_traces[0].root
-    spans = {s: root.find(s) for s in ("batch", "plan", "compile", "execute")}
+    names = ("serve.flush", "engine.plan", "engine.lookup", "engine.dispatch")
+    spans = {s: root.find(s) for s in names}
     missing = [s for s, sp in spans.items() if sp is None]
     assert not missing, f"partitioned trace missing spans: {missing}"
-    assert spans["plan"].attrs.get("backend") == "partitioned"
-    assert "nprobe" in spans["plan"].attrs
-    assert "hit" in spans["compile"].attrs
-    assert "partitions_probed" in spans["execute"].attrs, (
-        "execute span must carry the probe counters"
+    assert spans["engine.plan"].attrs.get("backend") == "partitioned"
+    assert "nprobe" in spans["engine.plan"].attrs
+    assert "hit" in spans["engine.lookup"].attrs
+    assert "partitions_probed" in spans["engine.dispatch"].attrs, (
+        "engine.dispatch span must carry the probe counters"
     )
     emit(bench, "partitioned_trace", "spans", len(spans))
     emit(bench, "partitioned_trace", "partitions_probed",
-         spans["execute"].attrs["partitions_probed"])
+         spans["engine.dispatch"].attrs["partitions_probed"])
 
     # -- gate 4: the Prometheus exposition parses ---------------------------
     text = prometheus_text(stats.registry)

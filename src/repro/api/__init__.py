@@ -39,7 +39,7 @@ Typical use::
 
 ``Engine.plan(batch, params)`` exposes the planner decision (backend,
 resolved quant mode, routing config, predicted brute/graph costs, reason)
-without executing it; ``Engine.executor.cache_info()`` reports plan-cache
+without executing it; ``Engine.executor.stats()`` reports plan-cache
 hits/misses.
 """
 from repro.api.engine import (

@@ -257,23 +257,32 @@ class BruteForceSearcher:
     def _adc_two_stage(self, engine, queries, qv, params):
         """ADC code scan → hard filter → exact rerank of the pool head.
         ``rerank_size`` bounds the full-precision stage exactly as in the
-        traversal path (0 → whole pool)."""
+        traversal path (0 → whole pool). Each stage is dispatched eagerly
+        and runs as programs of its own (``jit_adc_scan4_scores``,
+        ``jit_top_k``, ...); its host span names the dispatch gaps between
+        them. A ``jax.named_scope`` here would name nothing: a jitted
+        function called outside a trace lowers with a fresh name stack."""
         idx = engine.index
-        lut = idx.quant.lut(qv)  # OPQ rotation (if any) folds in here
-        scores = adc_scan(
-            lut, idx.quant.codes, jnp.asarray(queries.attrs, jnp.int32),
-            jnp.asarray(idx.attrs), mode="l2", packed=idx.quant.packed,
-        )  # (B, N) approximate squared L2 from codes only
-        ok = _ok_matrix(engine, queries)
-        pool = min(params.effective_pool, scores.shape[1])
-        pool = min(max(params.rerank_size or pool, params.k), pool)
-        neg, cand = jax.lax.top_k(-jnp.where(ok, scores, INF), pool)
-        cv = jnp.take(idx.features, jnp.maximum(cand, 0), axis=0)
-        rd = auto_mod.feature_sqdist(qv[:, None, :], cv)
-        rd = jnp.where(-neg < INF / 2, rd, INF)
-        res = _filtered_topk(
-            rd, jnp.ones_like(rd, bool), params.k, full_evals=pool, ids=cand
-        )
+        with obs_trace.span("brute.lut"):
+            lut = idx.quant.lut(qv)  # OPQ rotation (if any) folds in here
+        with obs_trace.span("brute.scan"):
+            scores = adc_scan(
+                lut, idx.quant.codes, jnp.asarray(queries.attrs, jnp.int32),
+                jnp.asarray(idx.attrs), mode="l2", packed=idx.quant.packed,
+            )  # (B, N) approximate squared L2 from codes only
+        with obs_trace.span("brute.select"):
+            ok = _ok_matrix(engine, queries)
+            pool = min(params.effective_pool, scores.shape[1])
+            pool = min(max(params.rerank_size or pool, params.k), pool)
+            neg, cand = jax.lax.top_k(-jnp.where(ok, scores, INF), pool)
+        with obs_trace.span("brute.rerank"):
+            cv = jnp.take(idx.features, jnp.maximum(cand, 0), axis=0)
+            rd = auto_mod.feature_sqdist(qv[:, None, :], cv)
+            rd = jnp.where(-neg < INF / 2, rd, INF)
+            res = _filtered_topk(
+                rd, jnp.ones_like(rd, bool), params.k, full_evals=pool,
+                ids=cand,
+            )
         n = idx.quant.codes.shape[0]
         return res._replace(
             n_code_evals=jnp.full((qv.shape[0],), n, jnp.int32)
@@ -564,18 +573,19 @@ class Engine:
         all-MATCH batch."""
         if isinstance(queries, tuple):
             queries = QueryBatch.match(*queries)
-        with obs_trace.span("plan") as sp:
-            plan = self.plan(queries, params)
-            if sp:
-                sp.set("backend", plan.backend)
-                sp.set("quant_mode", plan.quant_mode)
-                sp.set("reason", plan.reason)
-                sp.set("cost_brute", plan.cost_brute)
-                sp.set("cost_graph", plan.cost_graph)
-                if plan.backend == "partitioned":
-                    sp.set("nprobe", plan.nprobe)
-                    sp.set("sub_backend", plan.sub_backend)
-        return self.executor.run(queries, params, plan)
+        with obs_trace.span("engine.search"):
+            with obs_trace.span("engine.plan") as sp:
+                plan = self.plan(queries, params)
+                if sp:
+                    sp.set("backend", plan.backend)
+                    sp.set("quant_mode", plan.quant_mode)
+                    sp.set("reason", plan.reason)
+                    sp.set("cost_brute", plan.cost_brute)
+                    sp.set("cost_graph", plan.cost_graph)
+                    if plan.backend == "partitioned":
+                        sp.set("nprobe", plan.nprobe)
+                        sp.set("sub_backend", plan.sub_backend)
+            return self.executor.run(queries, params, plan)
 
     def _predicate_filter(
         self, res: SearchResult, queries: QueryBatch, full: bool

@@ -36,9 +36,6 @@ import dataclasses
 from collections import OrderedDict
 from typing import TYPE_CHECKING, Callable, NamedTuple, Optional
 
-import jax
-import numpy as np
-
 from repro.core import lru_get
 from repro.core import routing as routing_mod
 from repro.core.routing import RoutingConfig, SearchResult
@@ -103,9 +100,6 @@ class Executor:
             "max_entries": self.max_entries,
         }
 
-    # legacy name kept for callers that predate stats()
-    cache_info = stats
-
     def clear(self) -> None:
         """Drop every resident executable (the engine's index was swapped —
         e.g. a ``repro.mutable`` merge — so cached entry pools and closures
@@ -138,7 +132,9 @@ class Executor:
     def run(
         self, queries: QueryBatch, params: "SearchParams", plan: "Plan"
     ) -> SearchResult:
-        with obs_trace.span("compile") as sp:
+        # a lookup in the signature cache; a miss builds a closure, and the
+        # XLA compile (if any) happens at its first dispatch
+        with obs_trace.span("engine.lookup") as sp:
             sig = self.signature(queries, params, plan)
             size0 = len(self._cache)
             fn, hit = lru_get(
@@ -155,17 +151,10 @@ class Executor:
                 sp.set("hit", hit)
                 sp.set("backend", sig.backend)
                 sp.set("batch", sig.batch)
-        with obs_trace.span("execute") as sp:
-            res = fn(queries)
-            if sp:
-                # sampled path only: block so the span covers device time
-                # (the result is about to be consumed anyway), then read
-                # the host-side counters the result already carries
-                jax.block_until_ready(res.ids)
-                sp.set("n_hops", int(np.asarray(res.n_hops)))
-                sp.set("fp_evals", int(res.total_dist_evals))
-                sp.set("code_evals", int(res.total_code_evals))
-        return res
+        # the asynchronous launch of the device programs: the caller waits
+        # for the result (the batcher's ``engine.wait``), never this span
+        with obs_trace.span("engine.dispatch"):
+            return fn(queries)
 
     # -- compilation ---------------------------------------------------------
 

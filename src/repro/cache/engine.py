@@ -250,24 +250,24 @@ class TieredEngine:
     ):
         if isinstance(queries, tuple):
             queries = QueryBatch.match(*queries)
-        with obs_trace.span("plan") as sp:
-            plan = self.plan(queries, params)
-            if sp:
-                sp.set("backend", plan.backend)
-                sp.set("quant_mode", plan.quant_mode)
-                sp.set("reason", plan.reason)
-                sp.set("cost_brute", plan.cost_brute)
-                sp.set("cost_graph", plan.cost_graph)
-        sp = obs_trace.current()
-        if sp and self.tier is not None:
-            hot0 = self.tier.hot_row_hits
-            cold0 = self.tier.cold_row_gathers
-        res = self.executor.run(queries, params, plan)
-        if sp and self.tier is not None:
-            # the gather happened inside the executor's execute span; report
-            # the tier split for this request as counter deltas
-            sp.set("tier_hot_hits", self.tier.hot_row_hits - hot0)
-            sp.set("tier_cold_gathers", self.tier.cold_row_gathers - cold0)
+        with obs_trace.span("engine.search") as sp:
+            with obs_trace.span("engine.plan") as psp:
+                plan = self.plan(queries, params)
+                if psp:
+                    psp.set("backend", plan.backend)
+                    psp.set("quant_mode", plan.quant_mode)
+                    psp.set("reason", plan.reason)
+                    psp.set("cost_brute", plan.cost_brute)
+                    psp.set("cost_graph", plan.cost_graph)
+            if sp and self.tier is not None:
+                hot0 = self.tier.hot_row_hits
+                cold0 = self.tier.cold_row_gathers
+            res = self.executor.run(queries, params, plan)
+            if sp and self.tier is not None:
+                # the gather happened inside the executor's dispatch span;
+                # report the tier split for this request as counter deltas
+                sp.set("tier_hot_hits", self.tier.hot_row_hits - hot0)
+                sp.set("tier_cold_gathers", self.tier.cold_row_gathers - cold0)
         ids = np.asarray(res.ids)
         self.tracker.observe(ids)
         self._since_epoch += int(ids.shape[0])

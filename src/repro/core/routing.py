@@ -209,46 +209,59 @@ def _expand(
         elig = elig & state.active[:, None]
     exp_ids = jnp.where(elig, state.r_ids[:, :scope], INVALID)  # (B, scope)
 
+    # Each phase below runs under a ``jax.named_scope``: the ops it lowers
+    # to carry the phase in their metadata (a profile viewer's op names),
+    # which changes nothing that is computed.
+
     # --- gather neighbor candidates ------------------------------------------
-    nbrs = gops.gather_rows(graph, exp_ids)[:, :, :fanout]  # (B, scope, fanout)
-    cand = nbrs.reshape(b, scope * fanout)
-    cand = jnp.where(
-        (exp_ids < 0)[:, :, None].repeat(fanout, 2).reshape(b, -1), INVALID, cand
-    )
-    if use_visited:
-        seen = jnp.take_along_axis(
-            state.visited, jnp.maximum(cand, 0), axis=1
-        ).astype(bool)
-        cand = jnp.where(seen, INVALID, cand)
+    with jax.named_scope("traversal.gather"):
+        # (B, scope, fanout)
+        nbrs = gops.gather_rows(graph, exp_ids)[:, :, :fanout]
+        cand = nbrs.reshape(b, scope * fanout)
+        cand = jnp.where(
+            (exp_ids < 0)[:, :, None].repeat(fanout, 2).reshape(b, -1),
+            INVALID, cand,
+        )
+        if use_visited:
+            seen = jnp.take_along_axis(
+                state.visited, jnp.maximum(cand, 0), axis=1
+            ).astype(bool)
+            cand = jnp.where(seen, INVALID, cand)
 
     # --- score ----------------------------------------------------------------
-    cd = _score_candidates(
-        db_v, db_a, cand, qv, qa, metric_cfg, mask, quant, quant_mode
-    )
-    cd = jnp.where(cand < 0, INF, cd)
-    n_new_evals = (cand >= 0).sum(axis=1).astype(jnp.int32)
+    with jax.named_scope("traversal.score"):
+        cd = _score_candidates(
+            db_v, db_a, cand, qv, qa, metric_cfg, mask, quant, quant_mode
+        )
+        cd = jnp.where(cand < 0, INF, cd)
+        n_new_evals = (cand >= 0).sum(axis=1).astype(jnp.int32)
 
     # --- bookkeeping: expanded entries become checked; candidates visited ----
-    checked = state.checked.at[:, :scope].max(elig.astype(jnp.int8))
-    visited = state.visited
-    if use_visited:
-        # INVALID candidates are routed out of range and dropped.
-        safe_cand = jnp.where(cand >= 0, cand, state.visited.shape[1])
-        visited = visited.at[
-            jnp.arange(b)[:, None], safe_cand
-        ].set(jnp.int8(1), mode="drop")
+    with jax.named_scope("traversal.visited"):
+        checked = state.checked.at[:, :scope].max(elig.astype(jnp.int8))
+        visited = state.visited
+        if use_visited:
+            # INVALID candidates are routed out of range and dropped.
+            safe_cand = jnp.where(cand >= 0, cand, state.visited.shape[1])
+            visited = visited.at[
+                jnp.arange(b)[:, None], safe_cand
+            ].set(jnp.int8(1), mode="drop")
 
     # --- merge ----------------------------------------------------------------
-    old_watch = state.r_ids[:, :watch]
-    r_ids, r_d, checked = gops.merge_pools(
-        state.r_ids, state.r_d, cand, cd, pool,
-        pool_flags=checked,
-        cand_flags=jnp.zeros_like(cand, dtype=jnp.int8),
-    )
-    checked = jnp.where(r_ids < 0, jnp.int8(1), checked)  # pads never expand
-    improved = (r_ids[:, :watch] != old_watch).any(axis=1)
-    still_unchecked = ((checked[:, :scope] == 0) & (r_ids[:, :scope] >= 0)).any(axis=1)
-    active = state.active & (improved | still_unchecked)
+    with jax.named_scope("traversal.merge"):
+        old_watch = state.r_ids[:, :watch]
+        r_ids, r_d, checked = gops.merge_pools(
+            state.r_ids, state.r_d, cand, cd, pool,
+            pool_flags=checked,
+            cand_flags=jnp.zeros_like(cand, dtype=jnp.int8),
+        )
+        # pads never expand
+        checked = jnp.where(r_ids < 0, jnp.int8(1), checked)
+        improved = (r_ids[:, :watch] != old_watch).any(axis=1)
+        still_unchecked = (
+            (checked[:, :scope] == 0) & (r_ids[:, :scope] >= 0)
+        ).any(axis=1)
+        active = state.active & (improved | still_unchecked)
 
     return _State(
         r_ids=r_ids,

@@ -43,7 +43,7 @@ def main() -> None:
     from repro.data.synthetic import make_hybrid_dataset
     from repro.cache import ResultCache, TieredEngine
     from repro.mutable import CompactionPolicy, MutableEngine
-    from repro.obs import Tracer, dump_chrome_trace
+    from repro.obs import Tracer, dump_chrome_trace, recorder
     from repro.quant import QUANT_MODES, QuantConfig
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import (
@@ -109,10 +109,12 @@ def main() -> None:
                          "port: Prometheus text at /metrics, JSON at "
                          "/metrics.json (0 = pick an ephemeral port)")
     ap.add_argument("--trace-sample", type=int, default=0,
-                    help="sample every Nth request into a per-query trace "
-                         "(0 = tracing off; the no-op path costs nothing)")
+                    help="sample every Nth request into a per-query "
+                         "attribute tree (0 = none; every request's flat "
+                         "spans are recorded regardless)")
     ap.add_argument("--trace-out", default=None,
-                    help="write sampled traces as Chrome trace-event JSON "
+                    help="write sampled traces and every request's flat "
+                         "spans as Chrome trace-event JSON "
                          "(chrome://tracing / Perfetto); implies "
                          "--trace-sample 1 unless set explicitly")
     args = ap.parse_args()
@@ -257,8 +259,10 @@ def main() -> None:
         if srv.metrics_server is not None:
             print(f"metrics: {srv.metrics_server.url}/metrics "
                   f"(JSON at /metrics.json)")
+        t_serve = time.perf_counter_ns()
         futs = [srv.submit(r) for r in reqs]
         results = [f.result() for f in futs]
+    flat = recorder().between(t_serve, time.perf_counter_ns())
 
     done = [r for r in results if r.ok and hasattr(r, "ids")]
     snap = srv.stats.snapshot()
@@ -305,8 +309,11 @@ def main() -> None:
     if tracer is not None:
         traces = tracer.traces()
         if args.trace_out:
-            dump_chrome_trace(traces, args.trace_out)
-            print(f"  traces: {len(traces)} sampled -> {args.trace_out} "
+            dump_chrome_trace(traces, args.trace_out, flat.records)
+            print(f"  traces: {len(traces)} sampled and "
+                  f"{len(flat.records)} flat spans"
+                  f"{'' if flat.complete else ' (the ring dropped some)'} "
+                  f"-> {args.trace_out} "
                   "(open in chrome://tracing or ui.perfetto.dev)")
         elif traces:
             root = traces[-1].root

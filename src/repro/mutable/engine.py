@@ -298,7 +298,7 @@ class MutableEngine:
                 dead = np.isin(main_ids, banned)
                 main_ids = np.where(dead, INVALID, main_ids)
                 main_sq = np.where(dead, INF, main_sq)
-            with obs_trace.span("delta_scan") as sp:
+            with obs_trace.span("mutable.delta_scan") as sp:
                 d_ids, d_sq = self.delta.topk(
                     queries, k, self.engine.index.metric_cfg,
                     oracle=(plan.backend == "brute"),

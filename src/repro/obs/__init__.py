@@ -1,5 +1,6 @@
 """Observability layer: unified metrics registry, sampled per-query
-tracing, and Prometheus / Chrome-trace exporters.
+tracing, the process-wide span ring, and Prometheus / Chrome-trace
+exporters.
 
 This package depends only on the standard library — it sits *below*
 ``repro.api`` / ``repro.serve`` in the import graph so any layer can
@@ -13,7 +14,10 @@ from .registry import (
     MetricsRegistry,
     log_bounds,
 )
-from .trace import NOOP_SPAN, Span, Trace, Tracer, current, span
+from .trace import (
+    NOOP_SPAN, Span, Trace, Tracer, current, process_instruments, recorder,
+    span,
+)
 from .export import (
     chrome_trace,
     dump_chrome_trace,
@@ -38,6 +42,8 @@ __all__ = [
     "dump_chrome_trace",
     "json_snapshot",
     "log_bounds",
+    "process_instruments",
     "prometheus_text",
+    "recorder",
     "span",
 ]

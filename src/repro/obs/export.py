@@ -11,7 +11,7 @@ import re
 from typing import Iterable, List
 
 from .registry import MetricsRegistry
-from .trace import Span, Trace
+from .trace import Record, Span, Trace
 
 __all__ = [
     "chrome_trace",
@@ -86,19 +86,35 @@ def _span_events(
         _span_events(c, trace_id, out, pid, tid)
 
 
-def chrome_trace(traces: Iterable[Trace]) -> dict:
+def chrome_trace(
+    traces: Iterable[Trace], flat: Iterable[Record] = ()
+) -> dict:
     """Chrome Trace Event JSON (load in ``chrome://tracing`` or
-    ui.perfetto.dev).  Each trace renders on its own track (tid) so
-    overlapping sampled requests don't interleave visually."""
+    ui.perfetto.dev).  Each sampled trace renders on its own track (tid,
+    process 1) so overlapping sampled requests don't interleave visually;
+    ``flat`` ring records (``recorder().between(...).records``) render in
+    process 2, one track per recording thread, on the same clock."""
     events: List[dict] = []
     for tr in traces:
         _span_events(tr.root, tr.trace_id, events, pid=1, tid=tr.trace_id)
+    tracks: dict = {}
+    for r in flat:
+        events.append({
+            "name": r.name,
+            "ph": "X",
+            "ts": r.t0_ns / 1e3,
+            "dur": (r.t1_ns - r.t0_ns) / 1e3,
+            "pid": 2,
+            "tid": tracks.setdefault(r.tid, len(tracks)),
+        })
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",
     }
 
 
-def dump_chrome_trace(traces: Iterable[Trace], path: str) -> None:
+def dump_chrome_trace(
+    traces: Iterable[Trace], path: str, flat: Iterable[Record] = ()
+) -> None:
     with open(path, "w") as f:
-        json.dump(chrome_trace(traces), f, indent=2)
+        json.dump(chrome_trace(traces, flat), f, indent=2)

@@ -61,15 +61,23 @@ LATENCY_MS_BOUNDS = log_bounds(1e-3, 6e4, per_decade=10)
 
 
 class Counter:
-    """Monotone counter (thread-safe)."""
+    """Monotone counter (thread-safe). With ``source`` it counts nothing
+    itself and reads the callable's monotone total at collection time (a
+    count another object already keeps, such as the span ring's drops)."""
 
-    __slots__ = ("name", "help", "_lock", "_value")
+    __slots__ = ("name", "help", "_lock", "_value", "_source")
 
-    def __init__(self, name: str, help: str = ""):
+    def __init__(
+        self,
+        name: str,
+        help: str = "",
+        source: Optional[Callable[[], int]] = None,
+    ):
         self.name = name
         self.help = help
         self._lock = threading.Lock()
         self._value = 0
+        self._source = source
 
     def inc(self, n: int = 1) -> None:
         with self._lock:
@@ -77,7 +85,7 @@ class Counter:
 
     @property
     def value(self) -> int:
-        return self._value
+        return self._value if self._source is None else int(self._source())
 
 
 class Gauge:
@@ -267,6 +275,16 @@ class MetricsRegistry:
         return self._get_or_create(
             name, Histogram, lambda: Histogram(name, bounds, help)
         )
+
+    def adopt(self, instrument) -> None:
+        """Register an instrument created elsewhere under its own name —
+        the process-wide ones of ``repro.obs.trace`` (garbage-collection
+        pauses, XLA compiles, dropped spans) appear in every registry that
+        adopts them."""
+        with self._lock:
+            m = self._metrics.setdefault(instrument.name, instrument)
+        if m is not instrument:
+            raise ValueError(f"metric {instrument.name!r} already registered")
 
     def get(self, name: str) -> Optional[object]:
         with self._lock:

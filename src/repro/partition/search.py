@@ -176,7 +176,7 @@ class PartitionedSearcher:
         pidx = engine.index
         hard_all = plan.sub_backend == "brute" or params.enforce_equality
         probes = pidx.probe(queries, plan.nprobe, hard_all)  # (B, nprobe)
-        sp = obs_trace.current()  # the executor's "execute" span when sampled
+        sp = obs_trace.current()  # "engine.dispatch" when sampled
         if sp:
             # host-side probe attribution: -1 slots are summary-pruned
             sp.set("partitions_scored", int(pidx.n_partitions))

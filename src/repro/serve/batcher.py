@@ -65,6 +65,13 @@ class _Pending:
     backend: str  # B=1 planner decision, pinned at flush
     arrival: float  # driver-clock enqueue time
     sampled: bool = False  # tracer's per-request sampling decision
+    # (submit, pickup) on perf_counter_ns under the threaded front-end
+    inbox: Optional[Tuple[int, int]] = None
+
+    @property
+    def inbox_ms(self) -> float:
+        return 0.0 if self.inbox is None else (
+            self.inbox[1] - self.inbox[0]) / 1e6
 
 
 class RequestQueue:
@@ -140,19 +147,24 @@ class Microbatcher:
         return sig._replace(batch=0), plan.backend
 
     def enqueue(
-        self, req: Request, params: SearchParams, now: float
+        self, req: Request, params: SearchParams, now: float,
+        inbox: Optional[Tuple[int, int]] = None,
     ) -> List[Completed]:
         """Queue one admitted request; returns flushed responses (non-empty
-        only when this request filled the largest bucket)."""
-        qb = QueryBatch.from_queries([req.query])
-        key, backend = self.compile_key(qb, params)
-        sampled = (
-            self.tracer is not None and self.tracer.should_sample()
-        )
-        size = self.queue.push(
-            key, _Pending(req, qb, params, backend, now, sampled)
-        )
-        self.stats.record_queue_depth(self.queue.depth)
+        only when this request filled the largest bucket). ``inbox`` is
+        when the request was submitted and when the driver picked it up
+        (``time.perf_counter_ns``); the flush, off the pickup path, records
+        that wait and carries it out as ``Completed.inbox_ms``."""
+        with obs_trace.span("serve.enqueue"):
+            qb = QueryBatch.from_queries([req.query])
+            key, backend = self.compile_key(qb, params)
+            sampled = (
+                self.tracer is not None and self.tracer.should_sample()
+            )
+            size = self.queue.push(
+                key, _Pending(req, qb, params, backend, now, sampled, inbox)
+            )
+            self.stats.record_queue_depth(self.queue.depth)
         if size >= self.buckets[-1]:
             return self.flush(key, now)
         return []
@@ -184,15 +196,15 @@ class Microbatcher:
         self.stats.record_queue_depth(self.queue.depth)
         bucket = self.bucket_for(len(group))
         # one trace per flushed batch: the first sampled pending is the lead
-        # request the trace narrates; the engine spans (plan/compile/
-        # execute) attach under "batch" via the thread-local current span
+        # request the trace narrates; the flush span and the engine spans
+        # inside it attach under the root via the thread-local current span
         lead: Optional[_Pending] = None
         if self.tracer is not None:
             lead = next((p for p in group if p.sampled), None)
         trace = self.tracer.start("request") if lead is not None else None
         root = trace.root if trace is not None else obs_trace.NOOP_SPAN
-        with root.span("batch") as batch_sp:
-            with batch_sp.span("assemble"):
+        with root, obs_trace.span("serve.flush") as flush_sp:
+            with obs_trace.span("serve.assemble"):
                 qb = self._assemble(key, group, bucket)
             # pin the B=1 backend decision: the cost model's batch-amortized
             # crossover must not flip a coalesced batch onto other semantics
@@ -201,45 +213,65 @@ class Microbatcher:
             )
             t0 = time.perf_counter()
             res = self.engine.search(qb, params)
-            jax.block_until_ready(res.ids)
+            with obs_trace.span("engine.wait"):
+                jax.block_until_ready(res.ids)
             service_s = time.perf_counter() - t0
-            if batch_sp:
-                batch_sp.set("bucket", bucket)
-                batch_sp.set("batch_real", len(group))
-                batch_sp.set("pad_rows", bucket - len(group))
-                batch_sp.set("backend", group[0].backend)
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        self.stats.record_batch(len(group), bucket, service_s)
-        fill = len(group) / bucket
-        out = []
-        for i, p in enumerate(group):
-            queue_ms = max(now - p.arrival, 0.0) * 1e3
-            service_ms = service_s * 1e3
-            self.stats.record_completion(p.req.tenant, queue_ms, service_ms)
-            out.append(Completed(
-                request_id=p.req.request_id,
-                tenant=p.req.tenant,
-                ids=ids[i].copy(),
-                dists=dists[i].copy(),
-                queue_ms=queue_ms,
-                service_ms=service_ms,
-                bucket=bucket,
-                batch_fill=fill,
-            ))
+            if flush_sp:
+                flush_sp.set("bucket", bucket)
+                flush_sp.set("batch_real", len(group))
+                flush_sp.set("pad_rows", bucket - len(group))
+                flush_sp.set("backend", group[0].backend)
+                # read once the device is done, so sampling never blocks
+                # inside the dispatch it narrates
+                sp = flush_sp.find("engine.dispatch") or flush_sp
+                sp.set("n_hops", int(np.asarray(res.n_hops)))
+                sp.set("fp_evals", int(res.total_dist_evals))
+                sp.set("code_evals", int(res.total_code_evals))
+            with obs_trace.span("serve.fetch"):
+                ids = np.asarray(res.ids)
+                dists = np.asarray(res.dists)
+            self.stats.record_batch(len(group), bucket, service_s)
+            fill = len(group) / bucket
+            out = []
+            ring = obs_trace.recorder()
+            for i, p in enumerate(group):
+                queue_ms = max(now - p.arrival, 0.0) * 1e3
+                service_ms = service_s * 1e3
+                self.stats.record_completion(
+                    p.req.tenant, queue_ms, service_ms
+                )
+                if p.inbox is not None:
+                    ring.record("serve.inbox", *p.inbox)
+                    self.stats.record_inbox(p.inbox_ms)
+                out.append(Completed(
+                    request_id=p.req.request_id,
+                    tenant=p.req.tenant,
+                    ids=ids[i].copy(),
+                    dists=dists[i].copy(),
+                    queue_ms=queue_ms,
+                    service_ms=service_ms,
+                    bucket=bucket,
+                    batch_fill=fill,
+                    inbox_ms=p.inbox_ms,
+                ))
         if trace is not None:
-            # the queue wait ran on the driver clock (virtual in serve_loop,
-            # wall in ThreadedServer) — attach it as a synthetic span ending
-            # where the batch began, and pin the root to queue + batch so
-            # the trace decomposes the end-to-end latency exactly
+            # the inbox wait (perf clock) and the queue wait (the driver's
+            # clock: virtual in serve_loop, wall in ThreadedServer) end where
+            # the flush began; attach both as synthetic spans and pin the
+            # root to inbox + queue + flush so the trace decomposes the
+            # end-to-end latency exactly
             queue_s = max(now - lead.arrival, 0.0)
-            batch = root.children[0]
-            root.t0 = batch.t0 - queue_s
-            root.t1 = batch.t1
-            root.add("queue", root.t0, queue_s)
-            root.children.reverse()  # queue first, then batch
+            inbox_s = lead.inbox_ms * 1e-3
+            flush = next(c for c in root.children if c.name == "serve.flush")
+            root.t0 = flush.t0 - queue_s - inbox_s
+            root.t1 = flush.t1
+            root.children = []
+            root.add("serve.inbox", root.t0, inbox_s)
+            root.add("serve.queue", root.t0 + inbox_s, queue_s)
+            root.children.append(flush)
             root.set("tenant", lead.req.tenant)
             root.set("request_id", lead.req.request_id)
+            root.set("inbox_ms", lead.inbox_ms)
             root.set("queue_ms", queue_s * 1e3)
             root.set("service_ms", service_s * 1e3)
             root.set("cached", False)

@@ -23,6 +23,7 @@ from typing import Iterable, List, Optional, Tuple, Union
 
 from repro.api import Engine
 from repro.cache.results import ResultCache, result_key
+from repro.obs import trace as obs_trace
 from repro.obs.http import MetricsServer
 from repro.obs.trace import Tracer
 from repro.serve import request as request_mod
@@ -301,7 +302,7 @@ class ThreadedServer:
             if item[0] is _MERGE:  # defensive: worker normally applies it
                 self._finish_merge(item[1])
                 continue
-            req, _ = item
+            req = item[0]
             with self._lock:
                 fut = self._futures.pop(req.request_id, None)
                 self._pending_keys.pop(req.request_id, None)
@@ -379,7 +380,8 @@ class ThreadedServer:
                     return fut
                 self._pending_keys[req.request_id] = (key, epoch)
             self._futures[req.request_id] = fut
-        self._inbox.put((req, params))
+        # stamped for the inbox wait, which the worker closes at pickup
+        self._inbox.put((req, params, time.perf_counter_ns()))
         return fut
 
     def _submit_write(self, write: Union[Upsert, Delete]) -> "Future[Response]":
@@ -456,20 +458,38 @@ class ThreadedServer:
     # -- worker ---------------------------------------------------------------
 
     def _resolve(self, completions) -> None:
-        for c in completions:
-            with self._lock:
-                fut = self._futures.pop(c.request_id, None)
-                pk = self._pending_keys.pop(c.request_id, None)
-            if pk is not None:
-                # stored under the submit-time epoch: a write that landed
-                # mid-flight leaves this entry permanently stale (the
-                # lookup epoch check rejects it) — stale top-k is
-                # structurally unreachable
-                self._result_cache.insert(
-                    pk[0], c.ids, c.dists, self._now(), pk[1]
-                )
-            if fut is not None:
-                fut.set_result(c)
+        if not completions:
+            return
+        # the futures run their client callbacks on this thread
+        with obs_trace.span("serve.resolve"):
+            for c in completions:
+                with self._lock:
+                    fut = self._futures.pop(c.request_id, None)
+                    pk = self._pending_keys.pop(c.request_id, None)
+                if pk is not None:
+                    # stored under the submit-time epoch: a write that landed
+                    # mid-flight leaves this entry permanently stale (the
+                    # lookup epoch check rejects it) — stale top-k is
+                    # structurally unreachable
+                    self._result_cache.insert(
+                        pk[0], c.ids, c.dists, self._now(), pk[1]
+                    )
+                if fut is not None:
+                    fut.set_result(c)
+
+    def _take(self, timeout: float):
+        """The next inbox item, or None once ``timeout`` passes. Only a
+        wait is the ``serve.idle`` span: an item already there is taken
+        without one."""
+        try:
+            return self._inbox.get_nowait()
+        except queue_mod.Empty:
+            pass
+        with obs_trace.span("serve.idle"):
+            try:
+                return self._inbox.get(timeout=timeout)
+            except queue_mod.Empty:
+                return None
 
     def _run(self) -> None:
         window = self._mb.queue.window_s
@@ -479,17 +499,16 @@ class ThreadedServer:
                 timeout = window if deadline is None else max(
                     min(deadline - self._now(), window), 1e-4
                 )
-                try:
-                    item = self._inbox.get(timeout=timeout)
-                    if item[0] is _MERGE:  # fast swap between batches
-                        self._finish_merge(item[1])
-                    else:
-                        req, params = item
-                        self._resolve(
-                            self._mb.enqueue(req, params, self._now())
-                        )
-                except queue_mod.Empty:
-                    pass
+                item = self._take(timeout)
+                if item is not None and item[0] is _MERGE:
+                    self._finish_merge(item[1])  # fast swap between batches
+                elif item is not None:
+                    req, params, t_submit = item
+                    # the inbox wait ends here; the flush records it
+                    inbox = (t_submit, time.perf_counter_ns())
+                    self._resolve(self._mb.enqueue(
+                        req, params, self._now(), inbox=inbox
+                    ))
                 self._resolve(self._mb.flush_due(self._now()))
             self._resolve(self._mb.flush_all(self._now()))
         except BaseException as exc:  # fail loudly: never strand futures

@@ -64,11 +64,13 @@ class Request:
 class Completed:
     """Successful response — per-query slices of the coalesced batch result.
 
-    ``queue_ms`` is time spent waiting for the micro-batch window (the
-    driver's clock domain: virtual under ``serve_loop``, wall under the
-    threaded front-end); ``service_ms`` is the measured wall time of the
-    batch execution this request rode in; ``bucket``/``batch_fill`` say how
-    that batch was shaped (ladder size and real-row fraction).
+    ``queue_ms`` is time spent waiting for the micro-batch window, from
+    the worker's pickup to the flush (the driver's clock domain: virtual
+    under ``serve_loop``, wall under the threaded front-end); ``inbox_ms``
+    is the wait before the pickup; ``service_ms`` is the measured wall
+    time of the batch execution this request rode in;
+    ``bucket``/``batch_fill`` say how that batch was shaped (ladder size
+    and real-row fraction).
     """
 
     request_id: int
@@ -84,6 +86,9 @@ class Completed:
     #: ``bucket=0`` — no batch was ridden). Trailing default keeps every
     #: existing positional constructor call valid.
     cached: bool = False
+    #: submit to the worker's pickup under ``ThreadedServer`` (the inbox
+    #: wait, before ``queue_ms`` starts); 0.0 under ``serve_loop``
+    inbox_ms: float = 0.0
 
     @property
     def ok(self) -> bool:

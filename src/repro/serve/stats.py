@@ -14,9 +14,13 @@ and the serve-layer ``ResultCache`` — is registered as a pull-based
 them all with zero new work on any hot path.
 
 Latency is decomposed per request into ``queue`` (waiting for the
-micro-batch window — the driver's clock domain) and ``service`` (measured
-wall time of the coalesced batch execution the request rode in); the
-percentiles reported are end-to-end (queue + service).
+micro-batch window — the driver's clock domain, from the worker's pickup)
+and ``service`` (measured wall time of the coalesced batch execution the
+request rode in); the percentiles reported are queue + service. Under
+``ThreadedServer`` the wait before the pickup is ``serve_inbox_ms``. The
+process-wide ``process_gc_pause_ms``, ``xla_compiles_total`` and
+``obs_spans_dropped_total`` (``repro.obs.trace``) are adopted into the
+same registry.
 
 All recording paths hold one re-entrant lock: under ``ThreadedServer`` the
 submit path runs on caller threads while completions/batches come from the
@@ -31,6 +35,7 @@ import threading
 from collections import defaultdict
 from typing import TYPE_CHECKING, Optional
 
+from repro.obs import trace as obs_trace
 from repro.obs.registry import MetricsRegistry
 
 if TYPE_CHECKING:
@@ -85,6 +90,13 @@ class ServerStats:
         self._h_merge = self.registry.histogram(
             "serve_merge_ms", help="delta merge wall time (prepare + apply)"
         )
+        self._h_inbox = self.registry.histogram(
+            "serve_inbox_ms", help="per-request wait from submit to pickup"
+        )
+        # process-wide: gc pauses, XLA compiles, span records dropped
+        obs_trace.install()
+        for m in obs_trace.process_instruments():
+            self.registry.adopt(m)
         self.batches = 0
         self.real_rows = 0
         self.bucket_rows = 0
@@ -189,6 +201,10 @@ class ServerStats:
     def record_merge(self, wall_ms: float) -> None:
         """One completed delta→main merge (prepare + apply wall time)."""
         self._h_merge.observe(float(wall_ms))
+
+    def record_inbox(self, inbox_ms: float) -> None:
+        """One request's wait from submit to the worker's pickup."""
+        self._h_inbox.observe(inbox_ms)
 
     def record_queue_depth(self, depth: int) -> None:
         with self._lock:

@@ -362,11 +362,11 @@ class TestExecutorCache:
                               backend="graph")
         qb = QueryBatch.match(ds.query_features, ds.query_attrs)
         r1 = eng.search(qb, params)
-        before = eng.executor.cache_info()
+        before = eng.executor.stats()
         t0 = routing_mod.trace_count()
         r2 = eng.search(qb, params)
         assert routing_mod.trace_count() == t0  # zero new traces
-        after = eng.executor.cache_info()
+        after = eng.executor.stats()
         assert after["hits"] == before["hits"] + 1
         assert after["misses"] == before["misses"]
         np.testing.assert_array_equal(np.asarray(r1.ids), np.asarray(r2.ids))
@@ -379,10 +379,10 @@ class TestExecutorCache:
                               backend="graph")
         eng.search(QueryBatch.match(ds.query_features, ds.query_attrs),
                    params)
-        before = eng.executor.cache_info()
+        before = eng.executor.stats()
         eng.search(QueryBatch.match(ds.query_features[:8],
                                     ds.query_attrs[:8]), params)
-        after = eng.executor.cache_info()
+        after = eng.executor.stats()
         assert after["misses"] == before["misses"] + 1
 
     def test_different_predicate_kind_misses(self, ds, engines):
@@ -395,25 +395,25 @@ class TestExecutorCache:
             for i in range(8)
         ])
         eng.search(point, params)
-        before = eng.executor.cache_info()
+        before = eng.executor.stats()
         eng.search(interval, params)
-        after = eng.executor.cache_info()
+        after = eng.executor.stats()
         assert after["misses"] == before["misses"] + 1
         # …and repeating the interval batch is now a hit
         t0 = routing_mod.trace_count()
         eng.search(interval, params)
         assert routing_mod.trace_count() == t0
-        assert eng.executor.cache_info()["hits"] == after["hits"] + 1
+        assert eng.executor.stats()["hits"] == after["hits"] + 1
 
     def test_changed_params_miss(self, ds, engines):
         eng = engines["none"]
         qb = QueryBatch.match(ds.query_features[:8], ds.query_attrs[:8])
         eng.search(qb, SearchParams(k=7, pool_size=48, pioneer_size=6,
                                     seed=3, backend="graph"))
-        before = eng.executor.cache_info()
+        before = eng.executor.stats()
         eng.search(qb, SearchParams(k=7, pool_size=64, pioneer_size=6,
                                     seed=3, backend="graph"))
-        assert eng.executor.cache_info()["misses"] == before["misses"] + 1
+        assert eng.executor.stats()["misses"] == before["misses"] + 1
 
 
 # ---------------------------------------------------------------------------

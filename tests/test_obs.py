@@ -139,14 +139,16 @@ class TestRegistry:
 
 class TestTracer:
     def test_noop_path_allocates_nothing(self):
-        """With no active trace, span() returns the falsy singleton and the
-        instrumentation pattern allocates zero objects on the hot path."""
+        """With no sampled trace, a span's tree target is the falsy
+        singleton, and the instrumentation pattern retains no memory: the
+        span's own record goes to the preallocated ring."""
         assert current() is NOOP_SPAN
-        assert obs_trace.span("anything") is NOOP_SPAN
+        with obs_trace.span("anything") as sp:
+            assert sp is NOOP_SPAN
         assert not NOOP_SPAN
 
         def hot():
-            with obs_trace.span("plan") as sp:
+            with obs_trace.span("engine.plan") as sp:
                 if sp:  # pragma: no cover - never taken untraced
                     sp.set("k", 1)
 
@@ -178,14 +180,14 @@ class TestTracer:
     def test_span_stack_nesting_and_find(self):
         t = Tracer(sample_every=1)
         tr = t.start("request")
-        with tr.root.span("batch") as b:
+        with tr.root.span("serve.flush") as b:
             assert current() is b
-            with obs_trace.span("plan") as p:
+            with obs_trace.span("engine.plan") as p:
                 p.set("backend", "graph")
             assert current() is b
         assert current() is NOOP_SPAN
         t.finish(tr)
-        plan = tr.root.find("plan")
+        plan = tr.root.find("engine.plan")
         assert plan is not None and plan.attrs["backend"] == "graph"
         assert tr.root.duration >= plan.duration >= 0.0
 
@@ -217,15 +219,24 @@ class TestServeTrace:
         assert traces, "sample_every=1 must record every flushed batch"
         for tr in traces:
             root = tr.root
-            queue, batch = root.find("queue"), root.find("batch")
-            assert queue is not None and batch is not None
-            # exact by construction: root pinned to queue + batch
+            inbox, queue, batch = (root.find("serve.inbox"),
+                                   root.find("serve.queue"),
+                                   root.find("serve.flush"))
+            assert None not in (inbox, queue, batch)
+            assert [c.name for c in root.children] == [
+                "serve.inbox", "serve.queue", "serve.flush"]
+            assert inbox.duration == 0.0  # serve_loop has no inbox
+            # exact by construction: root pinned to inbox + queue + flush
             assert root.duration == pytest.approx(
-                queue.duration + batch.duration, abs=1e-9
+                inbox.duration + queue.duration + batch.duration, abs=1e-9
             )
-            # engine spans attached under batch via the thread-local stack
-            for name in ("assemble", "plan", "compile", "execute"):
+            # engine spans attached under the flush via the thread-local
+            # stack
+            for name in ("serve.assemble", "engine.search", "engine.plan",
+                         "engine.lookup", "engine.dispatch", "engine.wait",
+                         "serve.fetch"):
                 assert batch.find(name) is not None, name
+            assert "n_hops" in batch.find("engine.dispatch").attrs
             child_s = sum(c.duration for c in batch.children)
             assert child_s <= batch.duration + 1e-9
             assert child_s >= 0.5 * batch.duration
@@ -290,16 +301,17 @@ class TestExport:
     def test_chrome_trace_structure(self):
         t = Tracer(sample_every=1)
         tr = t.start("request")
-        with tr.root.span("batch"):
-            with obs_trace.span("plan") as p:
+        with tr.root.span("serve.flush"):
+            with obs_trace.span("engine.plan") as p:
                 p.set("backend", "graph")
         t.finish(tr)
         doc = chrome_trace(t.traces())
         events = doc["traceEvents"]
-        assert {e["name"] for e in events} >= {"request", "batch", "plan"}
+        assert {e["name"] for e in events} >= {
+            "request", "serve.flush", "engine.plan"}
         for e in events:
             assert e["ph"] == "X" and e["dur"] >= 0
-        plan = next(e for e in events if e["name"] == "plan")
+        plan = next(e for e in events if e["name"] == "engine.plan")
         assert plan["args"]["backend"] == "graph"
 
     def test_metrics_server_scrape(self):
