@@ -267,7 +267,8 @@ class BruteForceSearcher:
             lut = idx.quant.lut(qv)  # OPQ rotation (if any) folds in here
         with obs_trace.span("brute.scan"):
             scores = adc_scan(
-                lut, idx.quant.codes, jnp.asarray(queries.attrs, jnp.int32),
+                lut, idx.quant.scan_codes,
+                jnp.asarray(queries.attrs, jnp.int32),
                 jnp.asarray(idx.attrs), mode="l2", packed=idx.quant.packed,
             )  # (B, N) approximate squared L2 from codes only
         with obs_trace.span("brute.select"):

@@ -108,7 +108,8 @@ class _TieredBruteSearcher:
         qv = jnp.asarray(queries.vectors, jnp.float32)
         lut = idx.quant.lut(qv)
         scores = adc_scan(
-            lut, idx.quant.codes, jnp.asarray(queries.attrs, jnp.int32),
+            lut, idx.quant.scan_codes,
+            jnp.asarray(queries.attrs, jnp.int32),
             jnp.asarray(idx.attrs), mode="l2", packed=idx.quant.packed,
         )
         ok = engine_mod._ok_matrix(engine, queries)

@@ -27,17 +27,16 @@ def adc_scan(
     alpha: float = 1.0,
     mode: str = "auto",
     mask: Optional[Array] = None,
-    block_b: int = 8,
-    block_n: int = 256,
     packed: bool = False,
 ) -> Array:
     """(B, N) squared fused ADC distances (Pallas; interpreted on CPU).
     ``qa`` is (B, L) point targets or (B, L, 2) [lo, hi] interval targets.
-    ``packed`` selects the 4-bit nibble-packed kernel variant."""
+    ``codes`` are laid out by ``layout_codes``
+    (``QuantizedVectors.scan_codes``); ``packed`` selects the 4-bit
+    variant."""
     fn = adc_scan4_scores if packed else adc_scan_scores
     return fn(
         lut, codes, qa, xa, alpha=alpha, mode=mode, mask=mask,
-        block_b=block_b, block_n=block_n,
         interpret=interpret_mode(),
     )
 

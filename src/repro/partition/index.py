@@ -182,7 +182,9 @@ class PartitionedStableIndex:
                 sum(row_bucket(int(n)) for n in self.summaries.n_rows)
             ) or 1
         self.residency_rows = int(cap_rows)
-        self.store = SegmentStore(self._load_partition, self.residency_rows)
+        self.store = SegmentStore(
+            self._load_partition, self.residency_rows, codec=self.quant_for
+        )
 
     def _load_partition(self, pid: int) -> PartitionData:
         if self._parts is not None:

@@ -43,7 +43,7 @@ from repro.core.graph_ops import INF, INVALID
 from repro.core.routing import SearchResult
 from repro.obs import trace as obs_trace
 from repro.quant import adc_scan
-from repro.quant.store import is_packed_mode, is_pq_mode
+from repro.quant.store import is_pq_mode
 
 Array = jax.Array
 
@@ -232,9 +232,8 @@ class PartitionedSearcher:
             qv = jnp.asarray(sub.vectors, jnp.float32)
             lut = pidx.query_lut(qv)
             scores = adc_scan(
-                lut, part.codes, jnp.asarray(sub.attrs, jnp.int32),
-                part.attrs, mode="l2",
-                packed=is_packed_mode(plan.quant_mode),
+                lut, part.quant.scan_codes, jnp.asarray(sub.attrs, jnp.int32),
+                part.attrs, mode="l2", packed=part.quant.packed,
             )
             ok = _ok_local(part, sub)
             scores = jnp.where(ok, scores, INF)
@@ -320,7 +319,7 @@ class PartitionedSearcher:
                 part.features, part.attrs, part.graph, qv, targets,
                 pidx.metric_cfg, cfg, mask=maskq, entry_ids=eids,
                 seed=params.seed,
-                quant=pidx.quant_for(part.codes) if quant_on else None,
+                quant=part.quant if quant_on else None,
             )
             gid = jnp.where(
                 res.ids >= 0,

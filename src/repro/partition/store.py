@@ -28,7 +28,7 @@ import dataclasses
 import threading
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +75,7 @@ class ResidentPartition:
     row_ids: Array  # (b,) i32, -1 beyond n_real
     n_real: int
     n_pad: int  # the bucket b — rows charged against the residency cap
+    quant: Any = None  # the store's ``codec`` applied to ``codes``
 
 
 def _pad_rows(a: np.ndarray, b: int, fill=0) -> np.ndarray:
@@ -87,8 +88,11 @@ class SegmentStore:
 
     ``loader(pid)`` produces host ``PartitionData``; the store pads it to its
     row bucket, device-puts, and tracks rows against ``cap_rows`` with an
-    evict-before-load policy. Counters (``hits``/``loads``/``evictions``) and
-    gauges (``resident_rows``/``peak_resident_rows``) back both the scale
+    evict-before-load policy. ``codec``, when given, wraps a partition's
+    padded device codes once as it becomes resident (the index's codec
+    state and the layouts its scans read, ``ResidentPartition.quant``).
+    Counters (``hits``/``loads``/``evictions``) and gauges
+    (``resident_rows``/``peak_resident_rows``) back both the scale
     benchmark and the residency tests.
 
     Residency state and all counters are guarded by one re-entrant lock:
@@ -113,12 +117,14 @@ class SegmentStore:
         loader: Callable[[int], PartitionData],
         cap_rows: int,
         bucket_min: int = 256,
+        codec: Optional[Callable[[Array], Any]] = None,
     ):
         if cap_rows <= 0:
             raise ValueError("cap_rows must be positive")
         self.loader = loader
         self.cap_rows = int(cap_rows)
         self.bucket_min = int(bucket_min)
+        self.codec = codec
         self._lock = threading.RLock()
         self._resident: "OrderedDict[int, ResidentPartition]" = OrderedDict()
         self._pinned: set = set()
@@ -257,20 +263,25 @@ class SegmentStore:
         n = int(data.features.shape[0])
         b = row_bucket(n, self.bucket_min)
         dev = jax.device_put
+        codes = (
+            None
+            if data.codes is None
+            else dev(_pad_rows(np.asarray(data.codes), b))
+        )
         return ResidentPartition(
             features=dev(_pad_rows(np.asarray(data.features, np.float32), b)),
             attrs=dev(_pad_rows(np.asarray(data.attrs, np.int32), b)),
             graph=dev(
                 _pad_rows(np.asarray(data.graph, np.int32), b, fill=INVALID)
             ),
-            codes=(
-                None
-                if data.codes is None
-                else dev(_pad_rows(np.asarray(data.codes), b))
-            ),
+            codes=codes,
             row_ids=dev(_pad_rows(np.asarray(data.row_ids, np.int32), b, fill=-1)),
             n_real=n,
             n_pad=b,
+            quant=(
+                None if codes is None or self.codec is None
+                else self.codec(codes)
+            ),
         )
 
     def _evict_lru(self) -> bool:

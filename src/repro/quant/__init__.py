@@ -20,8 +20,10 @@ Codecs
            distances, S lookups+adds per candidate — never touching f32.
 ``pq4``  — 4-bit PQ: K=16 centroids per subspace, two codes packed per byte
            (⌈S/2⌉ bytes/vector — half of pq at equal S); the packed
-           ``adc_scan`` variant unpacks nibbles in-register and contracts an
-           S×16 one-hot LUT on the MXU.
+           ``adc_scan`` variant unpacks nibbles in-register and contracts
+           an S×16 one-hot LUT on the MXU. Both ``adc_scan`` variants read
+           the codes laid out once with rows on lanes
+           (``QuantizedVectors.scan_codes``).
 ``opq-pq`` / ``opq-pq4`` — OPQ: a learned orthogonal rotation before the
            subspace split (``opq.opq_train``, alternating minimization with
            a Procrustes update) cuts codebook error on correlated

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.adc_scan.adc_scan import layout_codes
 from repro.quant.opq import opq_train, rotate
 from repro.quant.pq import (
     PQCodebook,
@@ -99,13 +100,26 @@ class QuantConfig:
 
 @dataclasses.dataclass
 class QuantizedVectors:
-    """Codes + codec state for one database; ``None`` stands for mode='none'."""
+    """Codes + codec state for one database; ``None`` stands for mode='none'.
+
+    PQ modes also hold ``scan_codes``, the codes laid out for the
+    ``adc_scan`` kernels (``layout_codes``). It is derived from ``codes``
+    whenever an instance is made (build, load, a partition's slice,
+    ``dataclasses.replace``), so it can never lag them, and it is not
+    saved."""
 
     cfg: QuantConfig
     codes: Array  # sq8: (N, M) int8 · pq: (N, S) u8 · pq4: (N, ⌈S/2⌉) u8 packed
     sq_params: Optional[SQParams] = None
     codebook: Optional[PQCodebook] = None
     rotation: Optional[Array] = None  # (Mp, Mp) orthogonal, opq-* only
+    scan_codes: Optional[Array] = dataclasses.field(
+        init=False, default=None, repr=False, compare=False
+    )  # PQ: (⌈S/4⌉ or ⌈S/8⌉ packed, N_pad) int32 words, rows on lanes
+
+    def __post_init__(self):
+        if is_pq_mode(self.cfg.mode):
+            self.scan_codes = layout_codes(self.codes)
 
     @classmethod
     def build(cls, features, cfg: QuantConfig) -> Optional["QuantizedVectors"]:

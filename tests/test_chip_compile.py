@@ -16,7 +16,9 @@ machine that hold it do not block them. The persistent compilation cache is off 
 an entry written for a described chip cannot be read back without one.
 """
 import importlib.util
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,14 +73,15 @@ def _fits_one_chip(compiled) -> None:
 @pytest.mark.parametrize("packed", [False, True], ids=["8bit", "4bit"])
 def test_adc_scan_compiles_to_a_mosaic_kernel(one_chip, packed):
     from repro.kernels.adc_scan.adc_scan import (
-        adc_scan4_scores, adc_scan_scores,
+        adc_scan4_scores, adc_scan_scores, padded_rows,
     )
 
     s = 32
-    if packed:  # K=16 LUTs, two codes per byte: 16 packed bytes per row
-        fn, lut, codes = adc_scan4_scores, (B, s, 16), (N, s // 2, jnp.uint8)
+    if packed:  # K=16 LUTs, two codes per byte, eight per laid-out word
+        fn, lut, words = adc_scan4_scores, (B, s, 16), s // 8
     else:
-        fn, lut, codes = adc_scan_scores, (B, s, 256), (N, s, jnp.int32)
+        fn, lut, words = adc_scan_scores, (B, s, 256), s // 4
+    codes = (words, padded_rows(N), jnp.int32)
     scan = jax.jit(lambda lut, codes, qa, xa: fn(
         lut, codes, qa, xa, alpha=1.0, mode="auto", interpret=False))
     compiled = scan.lower(
@@ -88,6 +91,42 @@ def test_adc_scan_compiles_to_a_mosaic_kernel(one_chip, packed):
         _spec(one_chip, (N, L), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("b", [8, 32, 128])  # the server's buckets
+def test_served_4bit_scan_is_one_kernel_with_no_row_sized_layout_op(
+        one_chip, b):
+    """The brute path's scan at SIFT1M's N and S: one Mosaic kernel, and
+    no op of N elements or more (a convert, pad or copy of the codes or
+    the attributes) left in the program around it. The one exception is
+    the scores' own relayout at B = 128, where the chip's default layout
+    of a (128, N) f32 array puts the batch on the lanes. The LUT split
+    masks bit patterns: the chip keeps a bf16 value made inside a fusion
+    at f32, so a round trip through bf16 would cut nothing."""
+    from repro.kernels.adc_scan.adc_scan import adc_scan4_scores, padded_rows
+
+    n, s = 1_000_000, 32
+    scan = jax.jit(lambda lut, codes, qa, xa: adc_scan4_scores(
+        lut, codes, qa, xa, mode="l2", interpret=False))
+    compiled = scan.lower(
+        _spec(one_chip, (b, s, 16), jnp.float32),
+        _spec(one_chip, (s // 8, padded_rows(n)), jnp.int32),
+        _spec(one_chip, (b, L), jnp.int32),
+        _spec(one_chip, (n, L), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count(" and(") >= 2  # hi and mid of the LUT, masked
+    big = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\][^ ]* (\S+)\(", text):
+        dims, op = m.group(1), m.group(2)
+        size = math.prod(int(d) for d in dims.split(",") if d)
+        relayout = op == "copy" and dims == f"{b},{n}"
+        if size >= n and op not in ("parameter", "custom-call") \
+                and not relayout:
+            big.append(m.group(0))
+    assert not big, big
     _fits_one_chip(compiled)
 
 

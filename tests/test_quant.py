@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import Engine, QueryBatch, SearchParams
 from repro.core.baselines import brute_force_hybrid, recall_at_k
 from repro.core.help_graph import HelpConfig
 from repro.core.index import StableIndex
@@ -32,6 +33,9 @@ from repro.quant import (
     sq8_encode,
     unpack_nibbles,
 )
+from repro.kernels.adc_scan.adc_scan import layout_codes
+from repro.kernels.adc_scan.ref import adc_scan4_ref, adc_scan_ref
+from repro.quant import adc_scan
 from repro.quant.store import check_codec_spec, codec_spec
 
 try:
@@ -219,6 +223,65 @@ class TestNibblePacking:
                                                    pq_train_iters=3))
         assert q8.codes.dtype == jnp.uint8 and q4.codes.dtype == jnp.uint8
         assert q4.code_bytes * 2 == q8.code_bytes
+
+
+@pytest.mark.parametrize("mode", ["pq", "pq4"])
+class TestScanLayoutFreshness:
+    """The ADC scan's laid-out codes are derived wherever a
+    ``QuantizedVectors`` is made, so no path can scan stale codes."""
+
+    @staticmethod
+    def _cfg(mode):
+        return QuantConfig(mode=mode, pq_subspaces=8, pq_centroids=16,
+                           pq_train_iters=3)
+
+    @staticmethod
+    def _assert_fresh(q, attrs, qv):
+        np.testing.assert_array_equal(
+            np.asarray(q.scan_codes), np.asarray(layout_codes(q.codes))
+        )
+        lut = q.lut(jnp.asarray(qv))
+        qa = jnp.zeros((qv.shape[0], attrs.shape[1]), jnp.int32)
+        got = adc_scan(lut, q.scan_codes, qa, attrs, mode="l2",
+                       packed=q.packed)
+        ref = adc_scan4_ref if q.packed else adc_scan_ref
+        want = ref(lut, q.codes, qa, attrs, 1.0, mode="l2")
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-5
+        )
+
+    def test_appended_rows_reach_the_brute_scan(self, small_ds, mode):
+        x, a = small_ds.features, small_ds.attrs
+        eng = Engine.build(x[:600], a[:600], build_graph=False,
+                           quant_cfg=self._cfg(mode))
+        new_ids = np.arange(600, 604)
+        new_attrs = np.full((4, a.shape[1]), 3, np.int32)  # labels of their own
+        grown = eng.index.apply_rows(new_ids, x[600:604], new_attrs)
+        assert grown.quant.codes.shape[0] == 604
+        self._assert_fresh(grown.quant, grown.attrs, x[600:604])
+        res = Engine(grown).search(
+            QueryBatch.match(x[600:604], new_attrs),
+            SearchParams(k=1, backend="brute"),
+        )
+        np.testing.assert_array_equal(np.asarray(res.ids)[:, 0], new_ids)
+
+    def test_partition_slices_scan_their_own_rows(self, small_ds, mode):
+        x, a = small_ds.features[:600], small_ds.attrs[:600]
+        eng = Engine.build_partitioned(x, a, n_partitions=3,
+                                       build_graph=False,
+                                       quant_cfg=self._cfg(mode))
+        store = eng.index.store
+        for pid in range(3):
+            part = store.get(pid)
+            assert part.quant.codes is part.codes
+            self._assert_fresh(part.quant, part.attrs, x[:4])
+
+    def test_loaded_store_lays_out_its_codes(self, small_ds, tmp_path, mode):
+        q = QuantizedVectors.build(small_ds.features[:300], self._cfg(mode))
+        meta = q.save(str(tmp_path))
+        q2 = QuantizedVectors.load(str(tmp_path), meta)
+        self._assert_fresh(q2, jnp.asarray(small_ds.attrs[:300]),
+                           small_ds.features[:4])
 
 
 class TestOPQ:
